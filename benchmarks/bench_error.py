@@ -35,7 +35,8 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.configs.paper_rid import (PAPER_GRID, PAPER_TABLE5_ERRORS,
                                      SMALL_GRID)
 from repro.core import (error_bound, expected_sigma_kp1, rid,

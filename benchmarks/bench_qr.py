@@ -20,7 +20,7 @@ from repro.core import (blocked_pivoted_qr, cgs2_pivoted_qr, cholesky_qr2,
                         householder_qr)
 from repro.kernels import panel_deflate, panel_gram, project_out
 
-from .common import append_json_rows, emit, normalize_cost_analysis, time_fn
+from .common import append_json_rows, emit, time_fn
 
 PANEL_SWEEP = (16, 32, 64)
 
@@ -75,7 +75,7 @@ def fused_vs_split_sweep(panels, *, l=256, n=4096, k=128, json_path=None):
         split = jax.jit(lambda y, b=b: split_blocked_qr(y, k, b))
         t_fused = time_fn(fused, Y)
         t_split = time_fn(split, Y)
-        cost = normalize_cost_analysis(fused.lower(Y).compile())
+        cost = fused.lower(Y).compile().cost_analysis() or {}
         rows.append({"bench": "fused_panel_step", "l": l, "n": n, "k": k,
                      "panel": b, "split_s": t_split, "fused_s": t_fused,
                      "speedup": t_split / t_fused,

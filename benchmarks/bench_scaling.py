@@ -40,7 +40,10 @@ LINK = 50e9
 
 
 def worker(k, m, n, nproc, qr_impl="blocked", do_exec=False) -> dict:
+    # The worker is a lowering-only model on fake CPU devices: pin it to
+    # the CPU so it never competes with the parent for an accelerator.
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nproc}"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + \

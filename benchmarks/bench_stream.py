@@ -42,7 +42,8 @@ import tempfile
 import jax
 import numpy as np
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import rid_streamed
 from repro.obs import MeteredSource, tracing
 from repro.stream import ArraySource, FileSource

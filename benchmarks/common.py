@@ -1,6 +1,4 @@
-"""Shared benchmark utilities: robust timing, CSV emission, and the
-jax-version-spanning ``compiled.cost_analysis()`` normalization every
-lowering-based bench needs.
+"""Shared benchmark utilities: robust timing and CSV/JSON emission.
 
 Timing goes through the observability layer: ``time_fn`` brackets each
 iteration with an obs span (``bench.iter``) read off the sanctioned
@@ -17,10 +15,6 @@ import os
 
 import jax
 
-# Canonical home is the version-shim module; re-exported here so every
-# lowering-based bench (scaling_worker, bench_qr's fused sweep) keeps one
-# import site for its utilities.
-from repro.compat import normalize_cost_analysis  # noqa: F401
 from repro.obs import trace as obs_trace
 from repro.obs.clock import MONOTONIC
 

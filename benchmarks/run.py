@@ -65,6 +65,8 @@ def main():
                          "counts, wall times, bytes-per-device) — the perf "
                          "trajectory tracked across PRs")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     flags = ["--full"] if args.full else []
     t0 = time.time()
 

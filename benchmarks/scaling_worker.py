@@ -20,12 +20,12 @@ def main():
     do_exec = len(sys.argv) > 6 and sys.argv[6] == "1"
     import jax
     import jax.numpy as jnp
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
 
     from repro.core.distributed import rid_distributed
-    from repro.launch.dryrun import collective_bytes
+    from repro.analysis.hlo import collective_bytes
 
-    mesh = make_mesh((nproc,), ("data",),
+    mesh = jax.make_mesh((nproc,), ("data",),
                      axis_types=(AxisType.Auto,))
     key = jax.random.key(0)
     A = jax.ShapeDtypeStruct((m, n), jnp.float32)
@@ -35,12 +35,10 @@ def main():
                               sketch_kind="gaussian", qr_impl=qr_impl)
         return dec.B, dec.P
 
-    from .common import normalize_cost_analysis
-
     with mesh:
         lowered = jax.jit(run).lower(key, A)
         compiled = lowered.compile()
-    cost = normalize_cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     bytes_per_device = 0.0
     try:
         ma = compiled.memory_analysis()

@@ -31,7 +31,8 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 from repro.core import rid_distributed, shard_columns, spectral_norm_dense
 from repro.core.errors import error_bound, expected_sigma_kp1

@@ -43,7 +43,8 @@ import jax
 jax.config.update("jax_enable_x64", False)      # f32 matrix: GB go further
 
 import numpy as np
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import rid_streamed
 from repro.obs import MeteredSource
 from repro.runtime import FaultPlan, FlakySource, ProcessKilled

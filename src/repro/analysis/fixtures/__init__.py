@@ -18,7 +18,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...compat import shard_map
+from jax import shard_map
 from ..registry import EntryPoint, OverlapSpec
 
 __all__ = ["FIXTURES", "BAD_LINT_SRC", "BAD_SLEEP_SRC", "BAD_SERVER_SRC",

@@ -28,8 +28,8 @@ operand/output block contributes ``prod(block_shape) * itemsize`` bytes
 — once if its index_map is grid-invariant (resident across steps), twice
 otherwise (double-buffered pipeline).  Scratch shapes count once.  With
 ``measure_residency=True`` the example also runs for real and the shared
-sampler (:mod:`repro.obs.metrics`) plus
-``compat.normalize_cost_analysis`` record measured bytes as an ``info``
+sampler (:mod:`repro.obs.metrics`) plus XLA's
+``cost_analysis()`` record measured bytes as an ``info``
 finding next to the estimate.
 """
 from __future__ import annotations
@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import normalize_cost_analysis
 from ..obs.metrics import live_device_bytes
 from .report import Finding
 
@@ -297,7 +296,7 @@ def _measure_example(pkg, contract) -> list:
     jitted = jax.jit(partial(fn, **kwargs))
     try:
         lowered = jitted.lower(*args)
-        cost = normalize_cost_analysis(lowered.compile())
+        cost = lowered.compile().cost_analysis() or {}
         out = jitted(*args)
         jax.block_until_ready(out)
     except Exception as e:

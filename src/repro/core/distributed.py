@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from ..kernels.common import full_precision
 from .qr import (_h as _conj_t, pivoted_qr, resolve_norm_recompute,
                  resolve_panel)
 from .qr_dist import (identity_at_owned_pivots,
@@ -82,6 +82,7 @@ def _local_rid_fn(k: int, l: int, sketch_kind: str, axis: str,
     every device via a replicated key, so the replicated QR is bitwise
     identical too."""
 
+    @full_precision
     def fn(key, A_loc):
         Y_loc = _sketch(key, A_loc, l, kind=sketch_kind).Y          # (l, n_loc), no comm
         Y = lax.all_gather(Y_loc, axis, axis=1, tiled=True)          # (l, n) full gather
@@ -102,6 +103,7 @@ def _local_rid_panel_parallel_fn(k: int, l: int, sketch_kind: str, axis: str,
     ``core.qr_dist.panel_parallel_rid_interp_local`` body, with the
     shard-local sketch in front (no ``l x n`` array per device)."""
 
+    @full_precision
     def fn(key, A_loc):
         Y_loc = _sketch(key, A_loc, l, kind=sketch_kind).Y           # (l, n_loc)
         return panel_parallel_rid_interp_local(
@@ -168,9 +170,8 @@ def rid_distributed(key: jax.Array, A: jax.Array, k: int, *,
     # gather-and-replicate path) are bitwise identical on every device —
     # either recomputed from identical gathered inputs or produced by
     # collectives — but the rep-checker cannot prove it through the loop
-    # carries.  (``compat.shard_map`` translates this to check_rep=False on
-    # jax 0.4.x.)
-    mapped = shard_map(
+    # carries.
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), P(None, axis)),
         out_specs=(P(None, axis), P(), P(), r_spec),

@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.common import full_precision
 from ..kernels.panel_step import panel_step
 from ..obs import trace as obs_trace
 from .types import QRResult
@@ -108,6 +109,7 @@ def _downdate_res2(res2: jax.Array, w: jax.Array, p: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("k",))
+@full_precision
 def cgs2_pivoted_qr(Y: jax.Array, k: int) -> QRResult:
     """Greedy-pivoted CGS2 thin QR of the wide sketch ``Y`` (l x n).
 
@@ -156,6 +158,7 @@ def cgs2_pivoted_qr(Y: jax.Array, k: int) -> QRResult:
 
 
 @jax.jit
+@full_precision
 def householder_qr(Y: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Compact-WY-free Householder thin QR of a TALL panel (l x k, l >= k).
 
@@ -199,6 +202,7 @@ def householder_qr(Y: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 @jax.jit
+@full_precision
 def cholesky_qr2(Y: jax.Array) -> tuple[jax.Array, jax.Array]:
     """CholeskyQR2 of a TALL panel (l x k): pure-matmul orthonormalization.
 
@@ -345,6 +349,7 @@ def _fused_panel_update(Z, res2, picked, Q, piv, off: int, b: int):
 
 @partial(jax.jit, static_argnames=("k", "panel", "panel_impl",
                                    "norm_recompute"))
+@full_precision
 def blocked_pivoted_qr(Y: jax.Array, k: int, *, panel: int = 32,
                        panel_impl: str = "fused",
                        norm_recompute="auto") -> QRResult:
@@ -431,6 +436,7 @@ def blocked_pivoted_qr(Y: jax.Array, k: int, *, panel: int = 32,
 
 
 @partial(jax.jit, static_argnames=("off", "b"))
+@full_precision
 def _fused_panel_step_jit(Z, res2, picked, Q, piv, off: int, b: int):
     """Per-panel jit of the shared fused body, for the deep driver."""
     return _fused_panel_update(Z, res2, picked, Q, piv, off, b)

@@ -78,7 +78,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
+from ..kernels.common import full_precision
 from ..kernels.panel_gram import panel_gram
 from ..kernels.panel_step import panel_apply, panel_coeff
 from ..obs import trace as obs_trace
@@ -157,6 +157,7 @@ def _panel_qp_w(C: jax.Array, Z_loc: jax.Array
     return Qp, W
 
 
+@full_precision
 def panel_parallel_qr_local(Y_loc: jax.Array, k: int, *, axis: str,
                             ndev: int, panel: int = 32,
                             panel_impl: str = "fused",
@@ -317,6 +318,7 @@ def identity_at_owned_pivots(P_loc: jax.Array, piv: jax.Array, axis: str
                      P_loc)
 
 
+@full_precision
 def panel_parallel_rid_interp_local(Y_loc: jax.Array, k: int, *, axis: str,
                                     ndev: int, panel: int = 32,
                                     panel_impl: str = "fused",
@@ -391,7 +393,7 @@ def panel_parallel_pivoted_qr(Y: jax.Array, k: int, *, mesh: Mesh,
     fn = partial(panel_parallel_qr_local, k=k, axis=axis, ndev=ndev,
                  panel=panel, panel_impl=panel_impl,
                  norm_recompute=norm_recompute)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, axis),),
         out_specs=(P(), P(), P(None, axis)),
@@ -416,7 +418,7 @@ def panel_parallel_pivoted_qr(Y: jax.Array, k: int, *, mesh: Mesh,
                     recompute_ctr.add(1)
                 pos += b
             if obs_trace.deep_tracing():
-                from ..launch.dryrun import collective_bytes
+                from ..analysis.hlo import collective_bytes
                 compiled = jitted.lower(Y).compile()
                 obs_trace.counter("qr.collective_bytes").add(float(sum(
                     collective_bytes(compiled.as_text()).values())))
@@ -441,9 +443,9 @@ def _analysis_build(panel_impl: str):
         ndev = mesh.shape["data"]
         fn = partial(panel_parallel_qr_local, k=k, axis="data", ndev=ndev,
                      panel=b, panel_impl=panel_impl)
-        mapped = shard_map(fn, mesh=mesh, in_specs=(P(None, "data"),),
-                           out_specs=(P(), P(), P(None, "data")),
-                           check_vma=False)
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=(P(None, "data"),),
+                               out_specs=(P(), P(), P(None, "data")),
+                               check_vma=False)
         return mapped, (jax.ShapeDtypeStruct((l, n), jnp.float32),)
     return build
 

@@ -35,6 +35,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels.common import full_precision
 from .qr import pivoted_qr
 from .sketch import sketch
 from .tsolve import interp_from_qr
@@ -46,6 +47,7 @@ __all__ = ["rid", "rid_from_sketch"]
 
 @partial(jax.jit, static_argnames=("k", "qr_impl", "qr_panel",
                                    "qr_norm_recompute"))
+@full_precision
 def _qr_interp(Y: jax.Array, k: int, qr_impl: str, qr_panel: int,
                qr_norm_recompute):
     """Steps 2-3 (pivoted QR of the sketch + interpolation solve) as ONE

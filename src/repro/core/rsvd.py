@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels.common import full_precision
 from .qr import cholesky_qr2
 from .rid import rid
 from .types import IDResult, SVDResult
@@ -25,6 +26,7 @@ __all__ = ["rsvd", "rsvd_from_id"]
 
 
 @jax.jit
+@full_precision
 def rsvd_from_id(dec: IDResult) -> SVDResult:
     Qb, Rb = cholesky_qr2(dec.B.astype(dec.P.dtype))
     M = Rb @ dec.P                                   # (k, n), small

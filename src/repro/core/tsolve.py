@@ -17,10 +17,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.common import full_precision
+
 __all__ = ["solve_upper_triangular", "solve_upper_triangular_xla", "interp_from_qr"]
 
 
 @jax.jit
+@full_precision
 def solve_upper_triangular(R1: jax.Array, R2: jax.Array) -> jax.Array:
     """Back substitution: return ``T`` with ``triu(R1) @ T = R2``.
 
@@ -47,6 +50,7 @@ def solve_upper_triangular(R1: jax.Array, R2: jax.Array) -> jax.Array:
 
 
 @jax.jit
+@full_precision
 def solve_upper_triangular_xla(R1: jax.Array, R2: jax.Array) -> jax.Array:
     """XLA's native TriangularSolve — the production fast path."""
     return jax.scipy.linalg.solve_triangular(jnp.triu(R1), R2, lower=False)

@@ -26,6 +26,7 @@ engines are known to have (tests/test_error_bounds.py):
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Iterator, NamedTuple, Optional
 
 import jax
@@ -139,29 +140,46 @@ def spectrum_factors(key: jax.Array, m: int, n: int, spectrum: str, k: int, *,
                            sign_key=ks, m=m, dtype=dtype)
 
 
+def _mulmod(a: jax.Array, b: jax.Array, mod: int) -> jax.Array:
+    """``(a * b) % mod`` exactly in 32-bit integers, for integer arrays
+    ``0 <= a, b < mod <= 2**30``: Horner over ``L``-bit limbs of ``b``,
+    with ``L`` chosen so that no partial product reaches ``2**31``."""
+    bits = max(1, (mod - 1).bit_length())
+    limb = 31 - bits
+    r = jnp.zeros(jnp.broadcast_shapes(a.shape, b.shape), a.dtype)
+    for shift in reversed(range(0, bits, limb)):
+        part = (b >> shift) & ((1 << limb) - 1)
+        r = ((r << limb) % mod + (a * part) % mod) % mod
+    return r
+
+
 def spectrum_rows(f: SpectrumFactors, r0: int, r1: int) -> jax.Array:
     """Rows ``[r0, r1)`` of the factored matrix, in ``f.dtype``.  Each row
     depends only on its global index, so any chunking of ``[0, m)``
     concatenates to the same matrix."""
     i = jnp.arange(r0, r1)
     keys = jax.vmap(lambda ii: jax.random.fold_in(f.sign_key, ii))(i)
-    # i * f reaches ~m^2: form the products in f64 (exact below 2^53) and
-    # reduce modulo the basis period BEFORE the 2*pi scaling, so the
-    # trig arguments stay small and full-precision at any streaming m.
-    fi = i.astype(jnp.float64)
-    ff = jnp.asarray(np.asarray(f.freqs, np.float64))   # exact below 2^53
+    # i * f reaches ~m^2: reduce it modulo the basis period in exact
+    # integer arithmetic BEFORE the 2*pi scaling, so the trig arguments
+    # stay small and full-precision at any streaming m, with or without
+    # x64.
+    ff = jnp.asarray(f.freqs)[None, :]
+    # The generating GEMM runs at full precision: the spectrum is only
+    # exact if the rows are (a TPU's default f32 matmul rounds its inputs
+    # to bfloat16).
+    gemm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
     if jnp.issubdtype(f.dtype, jnp.complexfloating):
         phase = jax.vmap(lambda kk: jax.random.uniform(kk, ()))(keys)
         d = jnp.exp((2j * jnp.pi) * phase.astype(jnp.float64))
-        frac = jnp.mod(fi[:, None] * ff[None, :], float(f.m)) / f.m
+        frac = _mulmod(i[:, None], ff, f.m).astype(jnp.float64) / f.m
         U = (d[:, None] * jnp.exp((2j * jnp.pi) * frac)) / np.sqrt(f.m)
     else:
         d = jax.vmap(lambda kk: jax.random.rademacher(kk, (), jnp.float64))(keys)
         # cos(pi (i + 1/2) f / m) has period 4m in (2i+1) f
-        t = jnp.mod((2.0 * fi + 1.0)[:, None] * ff[None, :], 4.0 * f.m)
+        t = _mulmod((2 * i + 1)[:, None], ff, 4 * f.m).astype(jnp.float64)
         U = (d[:, None] * jnp.cos((jnp.pi / (2.0 * f.m)) * t)) * \
             np.sqrt(2.0 / f.m)
-    rows = (U * jnp.asarray(f.sig)[None, :]) @ f.V.conj().T
+    rows = gemm(U * jnp.asarray(f.sig)[None, :], f.V.conj().T)
     return rows.astype(f.dtype)
 
 

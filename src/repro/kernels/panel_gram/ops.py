@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..common import interpret_default, pad_to, round_up
+from ..common import full_precision, interpret_default, pad_to, round_up
 from .kernel import panel_gram_kernel
 from .ref import panel_gram_ref
 
@@ -14,6 +14,7 @@ __all__ = ["panel_gram"]
 
 
 @partial(jax.jit, static_argnames=("bn", "interpret"))
+@full_precision
 def panel_gram(c: jax.Array, z: jax.Array, *, bn: int = 128,
                interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """``(c^H c, c^H z)`` with ``c`` (l x b) a candidate panel and ``z``

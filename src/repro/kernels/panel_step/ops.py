@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..common import interpret_default, pad_to, round_up
+from ..common import full_precision, interpret_default, pad_to, round_up
 from .kernel import panel_apply_kernel, panel_coeff_kernel, panel_step_kernel
 from .ref import (panel_apply_norms_ref, panel_apply_ref, panel_coeff_ref,
                   panel_step_ref)
@@ -14,12 +14,19 @@ from .ref import (panel_apply_norms_ref, panel_apply_ref, panel_coeff_ref,
 __all__ = ["panel_step", "panel_coeff", "panel_apply"]
 
 
+# Widest sketch height at which the fused step's 256-lane slabs fit a
+# v5e's VMEM with its contractions at full precision (compile rehearsal:
+# l = 1800 fits, l = 2000 needs 128 lanes); taller sketches take 128.
+_WIDE_SLAB_MAX_L = 1024
+
+
 def _is_complex(*xs) -> bool:
     return any(jnp.issubdtype(x.dtype, jnp.complexfloating) for x in xs)
 
 
 @partial(jax.jit, static_argnames=("bn", "interpret", "emit_w"))
-def panel_step(c: jax.Array, z: jax.Array, *, bn: int = 256,
+@full_precision
+def panel_step(c: jax.Array, z: jax.Array, *, bn: int | None = None,
                interpret: bool | None = None, emit_w: bool = True
                ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused panel step: factor the candidate panel ``c`` (l x b) with
@@ -31,12 +38,15 @@ def panel_step(c: jax.Array, z: jax.Array, *, bn: int = 256,
     ``R = Q^H Y`` at the end) pass ``emit_w=False`` to elide its
     (b x n) HBM writeback; the ``W`` slot is then ``None``.  Real dtypes
     take the Pallas path; complex falls back to the oracle formula like
-    the other kernels (the production path is real)."""
+    the other kernels (the production path is real).  ``bn`` (slab
+    lanes) defaults to 256, or 128 above ``_WIDE_SLAB_MAX_L`` rows."""
     interpret = interpret_default() if interpret is None else interpret
     if _is_complex(c, z):
         qp, o, w, r2 = panel_step_ref(c, z)
         return qp, o, (w if emit_w else None), r2
     l, n = z.shape
+    if bn is None:
+        bn = 256 if l <= _WIDE_SLAB_MAX_L else 128
     np_ = round_up(n, bn)
     qp, o, w, r2 = panel_step_kernel(c, pad_to(z, (l, np_)), bn=bn,
                                      interpret=interpret, emit_w=emit_w)
@@ -44,6 +54,7 @@ def panel_step(c: jax.Array, z: jax.Array, *, bn: int = 256,
 
 
 @partial(jax.jit, static_argnames=("bn", "interpret"))
+@full_precision
 def panel_coeff(c: jax.Array, z: jax.Array, res2: jax.Array, *,
                 bn: int = 256, interpret: bool | None = None
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -64,6 +75,7 @@ def panel_coeff(c: jax.Array, z: jax.Array, res2: jax.Array, *,
 
 
 @partial(jax.jit, static_argnames=("bn", "interpret", "emit_norms"))
+@full_precision
 def panel_apply(qp: jax.Array, w: jax.Array, z: jax.Array, *,
                 bn: int = 256, interpret: bool | None = None,
                 emit_norms: bool = False):

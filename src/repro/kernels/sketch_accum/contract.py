@@ -16,9 +16,11 @@ f32 = jnp.float32
 
 
 def _example():
+    # Paper grid row 1's sketch width (l = 200, n = 2^14) over one
+    # 1024-row chunk: the VMEM check sees a real accumulator tile.
     from .ops import sketch_accum
-    x = jax.ShapeDtypeStruct((96, 1024), f32)
-    a = jax.ShapeDtypeStruct((1024, 512), f32)
+    x = jax.ShapeDtypeStruct((200, 1024), f32)
+    a = jax.ShapeDtypeStruct((1024, 2 ** 14), f32)
     return sketch_accum, (x, a), {}
 
 

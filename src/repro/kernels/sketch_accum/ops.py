@@ -16,14 +16,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..common import LANE, SUBLANE, interpret_default, pad_to, round_up
-from .kernel import ACCUM_BLOCK, sketch_accum_kernel
+from ..common import (LANE, SUBLANE, full_precision, interpret_default,
+                      pad_to, round_up)
+from .kernel import ACCUM_BLOCK, accum_tile, sketch_accum_kernel
 from .ref import accum_dtype_for, sketch_accum_ref
 
 __all__ = ["sketch_accum", "ACCUM_BLOCK", "accum_dtype_for"]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
+@full_precision
 def sketch_accum(x: jax.Array, a: jax.Array, acc: jax.Array | None = None, *,
                  interpret: bool | None = None) -> jax.Array:
     """``acc + x @ a`` in the accumulator dtype (``accum_dtype_for``), with
@@ -31,9 +33,10 @@ def sketch_accum(x: jax.Array, a: jax.Array, acc: jax.Array | None = None, *,
     blocks.  ``x``: (l, m) sketch-operator columns; ``a``: (m, n) row
     chunk; ``acc``: (l, n) running accumulator (``None`` = zeros).
 
-    Real dtypes take the Pallas kernel (one VMEM residency of ``acc``
-    across all blocks); complex falls back to the canonically-blocked ref
-    like the other kernels (TPU has no complex MXU path).
+    Real dtypes take the Pallas kernel (each ``acc`` column tile stays
+    in VMEM across all blocks); complex falls back to the
+    canonically-blocked ref like the other kernels (TPU has no complex
+    MXU path).
     """
     interpret = interpret_default() if interpret is None else interpret
     l, m = x.shape
@@ -54,5 +57,7 @@ def sketch_accum(x: jax.Array, a: jax.Array, acc: jax.Array | None = None, *,
     lp, np_ = round_up(l, SUBLANE), round_up(n, LANE)
     mp = round_up(m, ACCUM_BLOCK)
     out = sketch_accum_kernel(pad_to(x, (lp, mp)), pad_to(a, (mp, np_)),
-                              pad_to(acc, (lp, np_)), interpret=interpret)
+                              pad_to(acc, (lp, np_)),
+                              bn=accum_tile(lp, np_, jnp.dtype(adt).itemsize),
+                              interpret=interpret)
     return out[:l, :n]

@@ -30,7 +30,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import normalize_cost_analysis
+from repro.analysis.hlo import COLLECTIVE_RE, collective_bytes, shape_bytes
 from repro.configs import ARCHS, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, cell_applicable, input_specs
@@ -41,43 +41,6 @@ from repro.optim import CompressorConfig
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                             "experiments", "dryrun")
 
-# HLO collective result-shape parser: handles tuples and all dtypes.
-_COLL_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^)]*\)|\S+\[[^\]]*\]\S*)\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-done)?\(", re.M)
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-_DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
-                "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
-                "pred": 1, "c64": 8, "c128": 16}
-
-
-def _shape_bytes(type_str: str) -> int:
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(type_str):
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def collective_bytes(hlo_text: str) -> dict:
-    """Sum result-shape bytes per collective kind over the whole module."""
-    out: dict = {}
-    for m in _COLL_RE.finditer(hlo_text):
-        ty, kind = m.group(1), m.group(2)
-        # -start ops carry the real payload; -done would double count.
-        if m.group(0).rstrip("(").endswith("-done"):
-            continue
-        out[kind] = out.get(kind, 0) + _shape_bytes(ty)
-    return out
-
-
 def interpod_bytes(hlo_text: str, chips_per_pod: int = 256) -> float:
     """Bytes moved by collectives whose replica groups SPAN pods — the
     traffic that rides the slow inter-pod (DCN-class) links.  Groups are
@@ -87,7 +50,7 @@ def interpod_bytes(hlo_text: str, chips_per_pod: int = 256) -> float:
     import numpy as _np
     total = 0.0
     for line in hlo_text.splitlines():
-        m = _COLL_RE.search(line)
+        m = COLLECTIVE_RE.search(line)
         if not m or m.group(0).rstrip("(").endswith("-done"):
             continue
         gm = re.search(
@@ -118,7 +81,7 @@ def interpod_bytes(hlo_text: str, chips_per_pod: int = 256) -> float:
                         crosses = True
                         break
         if crosses:
-            total += _shape_bytes(m.group(1))
+            total += shape_bytes(m.group(1))
     return total
 
 
@@ -152,7 +115,7 @@ def lower_cell(cfg, shape_name: str, mesh, *, compress: bool = False,
 
 
 def analyze(compiled) -> dict:
-    cost = normalize_cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     mem = None
     try:
         ma = compiled.memory_analysis()

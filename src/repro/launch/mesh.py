@@ -15,7 +15,8 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-from ..compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 SINGLE_POD = ((16, 16), ("data", "model"))
 MULTI_POD = ((2, 16, 16), ("pod", "data", "model"))

@@ -143,7 +143,9 @@ class SpectrumSource:
     """Seeded generator source over a known-spectrum matrix.
 
     ``sigmas`` carries the EXACT singular values (``sigmas[k]`` is the
-    eq.(3) reference ``sigma_{k+1}``); rows are generated per chunk and
+    eq.(3) reference ``sigma_{k+1}``), and ``factors`` the factorization
+    itself (``data.synthetic.SpectrumFactors``), from which a reference
+    can evaluate ``||A x||`` without ``A``; rows are generated per chunk and
     never held all at once, so ``m`` can exceed device (and host)
     memory.  Generation is closed-form per global row index — the same
     matrix regardless of ``chunk_rows``.
@@ -155,9 +157,9 @@ class SpectrumSource:
         if chunk_rows < 1:
             raise ValueError(f"need chunk_rows >= 1, got "
                              f"chunk_rows={chunk_rows}")
-        self._factors: SpectrumFactors = spectrum_factors(
+        self.factors: SpectrumFactors = spectrum_factors(
             key, m, n, spectrum, k, r=r, dtype=dtype, floor=floor)
-        self.sigmas = np.asarray(self._factors.sig)
+        self.sigmas = np.asarray(self.factors.sig)
         self.shape = (m, n)
         self.dtype = jnp.dtype(dtype)
         self.chunk_rows = int(chunk_rows)
@@ -178,7 +180,7 @@ class SpectrumSource:
 
     def chunk(self, c: int) -> jax.Array:
         r0, r1 = chunk_bounds(self, c)
-        return spectrum_rows(self._factors, r0, r1)
+        return spectrum_rows(self.factors, r0, r1)
 
     def materialize(self) -> np.ndarray:
         """Concatenate every chunk — small-``m`` tests only."""

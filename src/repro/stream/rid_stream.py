@@ -144,7 +144,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..checkpoint.store import CheckpointManager, latest_step, restore_pytree
-from ..compat import shard_map
 from ..core.qr import resolve_norm_recompute, resolve_panel
 from ..core.qr_dist import panel_parallel_rid_interp_local
 from ..core.rid import _cast_interp, _qr_interp
@@ -179,9 +178,9 @@ def _sharded_accum_fn(mesh: Mesh, axis: str):
     def step(x, a_loc, acc_loc):
         return sketch_accum(x, a_loc, acc_loc)
 
-    return jax.jit(shard_map(step, mesh=mesh,
-                             in_specs=(PartitionSpec(), spec, spec),
-                             out_specs=spec, check_vma=False))
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=(PartitionSpec(), spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 @lru_cache(maxsize=None)
@@ -196,10 +195,10 @@ def _sharded_qr_interp_fn(mesh: Mesh, axis: str, k: int, qr_panel: int,
     fn = partial(panel_parallel_rid_interp_local, k=k, axis=axis, ndev=ndev,
                  panel=qr_panel, norm_recompute=qr_norm_recompute)
     spec = PartitionSpec(None, axis)
-    mapped = shard_map(fn, mesh=mesh, in_specs=(spec,),
-                       out_specs=(spec, PartitionSpec(), PartitionSpec(),
-                                  spec),
-                       check_vma=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                           out_specs=(spec, PartitionSpec(), PartitionSpec(),
+                                      spec),
+                           check_vma=False)
     return jax.jit(mapped)
 
 
@@ -525,6 +524,9 @@ def rid_streamed(key: jax.Array, source: ChunkSource, k: int, *,
                                          "acc": acc})
                     Y = finalize_gaussian_sketch(acc, l, dtype)
                     p1.block_on(Y)
+                    # Drop the pass-1 buffers before the QR: at row-8
+                    # widths the accumulator alone is 2 GB on one chip.
+                    acc = cur = nxt = None
 
             # ---- steps 2-3: identical jit boundary to the in-memory path
             if interp is None:
@@ -642,11 +644,11 @@ def _analysis_build_stream_sharded_step():
         return panel_parallel_rid_interp_local(Y_loc, k, axis="data",
                                                ndev=ndev, panel=7)
 
-    step = shard_map(local, mesh=mesh,
-                     in_specs=(PartitionSpec(), spec, spec),
-                     out_specs=(spec, PartitionSpec(), PartitionSpec(),
-                                spec),
-                     check_vma=False)
+    step = jax.shard_map(local, mesh=mesh,
+                         in_specs=(PartitionSpec(), spec, spec),
+                         out_specs=(spec, PartitionSpec(), PartitionSpec(),
+                                    spec),
+                         check_vma=False)
     return step, (jax.ShapeDtypeStruct((l, rows), jnp.float32),
                   jax.ShapeDtypeStruct((rows, n), jnp.float32),
                   jax.ShapeDtypeStruct((l, n), jnp.float32))

@@ -343,6 +343,40 @@ def test_runner_end_to_end_and_report_schema(tmp_path):
                 "fingerprint"} <= set(f)
 
 
+def test_collective_bytes_without_touching_xla_flags():
+    """The decomposition path reads collective payloads from
+    ``analysis.hlo``, whose import sets nothing: after importing the core
+    and running the deep-traced sharded QR (the caller of
+    ``collective_bytes``), ``XLA_FLAGS`` is still unset."""
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1]
+                            / "src") + os.pathsep + env.get("PYTHONPATH", "")
+    code = """
+import os, jax, jax.numpy as jnp
+from jax.sharding import Mesh
+import numpy as np
+import repro.core, repro.stream
+from repro.core import panel_parallel_pivoted_qr
+from repro.obs import tracing
+from repro.analysis.hlo import collective_bytes
+mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+with tracing(deep=True):
+    panel_parallel_pivoted_qr(jnp.ones((16, 64)), 4, mesh=mesh, axis="data",
+                              panel=4)
+assert "XLA_FLAGS" not in os.environ, os.environ["XLA_FLAGS"]
+line = "  %ar = f32[16,64]{1,0} all-reduce(f32[16,64]{1,0} %x), to_apply=%add"
+assert collective_bytes(line) == {"all-reduce": 16 * 64 * 4}
+print("OK")
+"""
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
 @pytest.mark.slow
 def test_cli_gates_on_new_findings(subproc, tmp_path):
     # full CLI in the CI environment: clean tree -> exit 0; a baseline

@@ -200,3 +200,48 @@ def test_property_rid_idempotent_on_exact_rank(k, seed):
     dec2 = rid(jax.random.fold_in(key, 3), A2, k, sketch_kind="gaussian")
     assert float(spectral_norm_dense(A2 - dec2.reconstruct())) < 1e-9 * \
         max(1.0, float(spectral_norm_dense(A2)))
+
+
+# ------------------------------------------------------------- precision
+
+def _stage(name):
+    """``(fn, args)`` of one jit stage of the decomposition, f32."""
+    from jax.sharding import Mesh
+
+    from repro.core import rid_distributed
+    from repro.core.rid import _qr_interp
+    from repro.kernels import sketch_accum
+    from repro.stream.rid_stream import _sharded_qr_interp_fn
+    S = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    return {
+        "sketch_accum": (sketch_accum, (S((48, 256), f32), S((256, 400), f32))),
+        "qr_interp": (lambda Y: _qr_interp(Y, 21, "blocked", 7, "auto"),
+                      (S((48, 400), f32),)),
+        "sharded_qr_interp": (_sharded_qr_interp_fn(mesh, "data", 21, 7,
+                                                    "auto"),
+                              (S((48, 400), f32),)),
+        "rid_distributed": (lambda key, A: rid_distributed(
+            key, A, 21, mesh=mesh, sketch_kind="gaussian",
+            qr_impl="panel_parallel", qr_panel=7).P,
+            (jax.random.key(0), S((256, 400), f32))),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ["sketch_accum", "qr_interp",
+                                  "sharded_qr_interp", "rid_distributed"])
+def test_main_path_matmuls_at_full_precision(name):
+    """Every matmul a stage traces — XLA's and the Pallas kernels' —
+    carries ``HIGHEST``.  At a TPU's default precision (one bfloat16
+    pass) the ID was wrong at paper grid row 8 on a v5e (relative error
+    above 1), while the CPU computes f32 dots in full either way, so no
+    numeric test here would notice the pin going missing."""
+    from repro.analysis.jaxpr import iter_eqns
+    fn, args = _stage(name)
+    dots = [e for e in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert dots
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    assert all(e.params["precision"] == highest for e in dots), \
+        {e.params["precision"] for e in dots}

@@ -12,7 +12,8 @@ from repro.core import rid_distributed, rid, spectral_norm_dense
 key = jax.random.key(0)
 m, n, k = 512, 400, 12
 A = jax.random.normal(key, (m, k)) @ jax.random.normal(jax.random.fold_in(key,1), (k, n))
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 mesh = make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 dec = rid_distributed(jax.random.key(2), A, k, mesh=mesh, axis="data", sketch_kind="gaussian")
 err = float(spectral_norm_dense(A - dec.B @ dec.P)) / float(spectral_norm_dense(A))
@@ -30,7 +31,8 @@ print("OK", err)
 def test_train_step_sharded_with_compression(subproc):
     r = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config
 from repro.launch.steps import TrainConfig, jit_train_step, init_train_state
 from repro.optim import CompressorConfig
@@ -67,7 +69,8 @@ def test_elastic_reshard_restore(subproc):
     r = subproc("""
 import tempfile, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.checkpoint import save_pytree, restore_pytree
 
 devs = jax.devices()
@@ -93,7 +96,8 @@ def test_train_loop_failure_recovery(subproc):
     from checkpoint on the smaller mesh, losses replay deterministically."""
     r = subproc("""
 import tempfile, jax
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config
 from repro.launch.steps import TrainConfig
 from repro.launch.train import train_loop

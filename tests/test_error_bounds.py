@@ -35,7 +35,8 @@ import pytest
 
 jax.config.update("jax_enable_x64", True)
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import (error_bound, pivoted_qr, rid, rid_distributed,
                         shard_columns, spectral_norm_dense)
 from repro.core.qr import resolve_panel

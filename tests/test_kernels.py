@@ -87,9 +87,12 @@ def _skip_without_x64(dtype):
                                    (100, 777, 129), (17, 64, 200)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_sketch_accum_sweep(l, m, n, dtype):
-    """Kernel vs the canonically-blocked oracle (identical association,
-    so the comparison is EXACT) and vs a plain dot (tolerance)."""
-    from repro.kernels.sketch_accum import sketch_accum
+    """The replay contract, EXACT: one call over all of m is bit-equal to
+    every ACCUM_BLOCK-multiple chunking of the rows.  Against the
+    canonically-blocked oracle and a plain dot the kernel agrees within
+    tolerance: its padded tiles are other GEMM shapes than the oracle's
+    unpadded dots, and nothing promises those round alike."""
+    from repro.kernels.sketch_accum import ACCUM_BLOCK, sketch_accum
     from repro.kernels.sketch_accum.ref import (accum_dtype_for,
                                                 sketch_accum_ref)
     _skip_without_x64(dtype)
@@ -98,13 +101,18 @@ def test_sketch_accum_sweep(l, m, n, dtype):
     adt = accum_dtype_for(dtype)
     acc0 = jax.random.normal(key(9), (l, n), dtype=adt)
     got = sketch_accum(x, a, acc0)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(sketch_accum_ref(
-                                      x.astype(adt), a.astype(adt), acc0)))
+    for chunk in (ACCUM_BLOCK, 2 * ACCUM_BLOCK):
+        acc = acc0
+        for r0 in range(0, m, chunk):
+            acc = sketch_accum(x[:, r0:r0 + chunk], a[r0:r0 + chunk], acc)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(acc))
+    atol = (1e-4 if dtype == jnp.float32 else 1e-10) * np.sqrt(m)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(sketch_accum_ref(
+                                   x.astype(adt), a.astype(adt), acc0)),
+                               atol=atol)
     want = acc0 + jnp.dot(x, a, preferred_element_type=adt)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=(1e-4 if dtype == jnp.float32 else 1e-10)
-                               * np.sqrt(m))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.complex64, jnp.complex128])

@@ -17,7 +17,8 @@ def test_hint_noop_without_mesh():
 def test_hint_in_subprocess_mesh(subproc):
     r = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.models.pshard import hint, dp_axes
 
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import rid_distributed
 
 
@@ -15,7 +16,8 @@ from repro.core import rid_distributed
 PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 jax.config.update("jax_enable_x64", True)
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import (rid_distributed, shard_columns, spectral_norm_dense,
                         panel_parallel_pivoted_qr)
 from repro.core.qr import cgs2_pivoted_qr, blocked_pivoted_qr
@@ -370,7 +372,8 @@ def test_uneven_shard_raises(subproc):
     """n not divisible by the mesh axis raises eagerly, before tracing."""
     r = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core import rid_distributed
 mesh = make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 A = jnp.zeros((64, 100))            # 100 % 8 != 0

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.stream import ArraySource, rid_streamed
 
 
@@ -24,7 +25,8 @@ PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 jax.config.update("jax_enable_x64", True)
 from jax.sharding import Mesh
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.stream import ArraySource, rid_streamed
 from repro.kernels.sketch_accum import ACCUM_BLOCK
 

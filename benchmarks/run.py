@@ -75,9 +75,8 @@ def main():
     js = ["--json", args.bench_json] if args.bench_json else []
     guard = _RowGuard(args.bench_json)
 
-    from . import (bench_error, bench_overlap, bench_qr, bench_scaling,
-                   bench_sketch, bench_stream, bench_total, bench_tsolve,
-                   roofline)
+    from . import (bench_error, bench_qr, bench_scaling, bench_sketch,
+                   bench_stream, bench_total, bench_tsolve, roofline)
 
     section("Table 1: total RID runtime (phases)")
     bench_total.main(flags)
@@ -99,10 +98,6 @@ def main():
     section(title)
     with guard.expect_rows(title):
         bench_stream.main(flags + js)
-    title = "Runtime overlap gate: measured H2D-hidden fraction"
-    section(title)
-    with guard.expect_rows(title):
-        bench_overlap.main(flags + js + ["--gate"])
     if not args.skip_scaling:
         title = "Figures 1-2: structural parallel scaling"
         section(title)

@@ -26,9 +26,17 @@ Step 2 has two engines, selected by ``qr_impl``:
                     the MXU-bound production DEFAULT;
   * ``"cgs2"``    — the paper's per-column iterated Gram-Schmidt
                     (``cgs2_pivoted_qr``), kept as the parity oracle.
+
+OBSERVABILITY: under a ``repro.obs`` tracer, ``rid`` opens the span
+``rid`` (attrs ``m``, ``n``, ``k``, ``l``, ``sketch_kind``) with the
+children ``rid.sketch``, ``rid.qr_interp`` and ``rid.gather`` (the last
+two also from ``rid_from_sketch`` called directly).  They time the
+host's dispatch of each stage and never block, so the schedule is the
+untraced one; inside a caller's jit no span opens.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Optional
 
@@ -36,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.common import full_precision
+from ..obs import trace as obs_trace
 from .qr import pivoted_qr
 from .sketch import sketch
 from .tsolve import interp_from_qr
@@ -71,12 +80,23 @@ def _cast_interp(P: jax.Array, a_dtype) -> jax.Array:
     return P
 
 
+def _span(A, name: str, **attrs):
+    """The ambient span ``name``, or none where ``A`` is a tracer: under a
+    caller's jit it would time the trace, not the run."""
+    if isinstance(A, jax.core.Tracer):
+        return contextlib.nullcontext()
+    return obs_trace.span(name, **attrs)
+
+
 def rid_from_sketch(A: jax.Array, Y: jax.Array, k: int, *,
                     qr_impl: str = "blocked", qr_panel: int = 32,
                     qr_norm_recompute="auto") -> IDResult:
     """Steps 2-4 given an existing sketch ``Y`` (l x n)."""
-    P, piv, Q, R = _qr_interp(Y, k, qr_impl, qr_panel, qr_norm_recompute)
-    B = jnp.take(A, piv, axis=1)
+    with _span(A, "rid.qr_interp"):
+        P, piv, Q, R = _qr_interp(Y, k, qr_impl, qr_panel,
+                                  qr_norm_recompute)
+    with _span(A, "rid.gather"):
+        B = jnp.take(A, piv, axis=1)
     return IDResult(B=B, P=_cast_interp(P, A.dtype), J=piv, Q=Q, R=R)
 
 
@@ -104,9 +124,12 @@ def rid(key: jax.Array, A: jax.Array, k: int, *, l: Optional[int] = None,
     """
     l = 2 * k if l is None else l
     check_l_ge_k(l, k)
-    Y = sketch(key, A, l, kind=sketch_kind).Y
-    return rid_from_sketch(A, Y, k, qr_impl=qr_impl, qr_panel=qr_panel,
-                           qr_norm_recompute=qr_norm_recompute)
+    m, n = A.shape
+    with _span(A, "rid", m=m, n=n, k=k, l=l, sketch_kind=sketch_kind):
+        with _span(A, "rid.sketch"):
+            Y = sketch(key, A, l, kind=sketch_kind).Y
+        return rid_from_sketch(A, Y, k, qr_impl=qr_impl, qr_panel=qr_panel,
+                               qr_norm_recompute=qr_norm_recompute)
 
 
 # ------------------------------------------------------------- analysis

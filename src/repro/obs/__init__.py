@@ -10,9 +10,9 @@ trace-event JSON, and Prometheus text (``obs.export``,
 ``obs.telemetry``).
 
 On top of the recording layer: ``obs.timeline`` reconstructs a finished
-trace into per-phase critical path, measured overlap efficiency, and
-throughput; ``obs.progress`` publishes live done/total/ETA status for
-in-flight jobs; ``obs.telemetry`` serves ``/metrics`` + ``/healthz`` +
+trace into per-phase critical path, psum overlap, and throughput;
+``obs.progress`` publishes live done/total/ETA status for in-flight
+jobs; ``obs.telemetry`` serves ``/metrics`` + ``/healthz`` +
 ``/progress`` over stdlib HTTP.  See obs/README.md for the span and
 metric catalog, the viewing instructions, and the "watch a long job"
 quickstart.
@@ -24,7 +24,7 @@ from .metrics import (Counter, Gauge, Histogram, MeteredSource,
                       MetricsRegistry, live_device_bytes)
 from .progress import ProgressReporter
 from .telemetry import PrometheusExporter, TelemetryServer, prometheus_text
-from .timeline import PhaseStat, Timeline, TSpan, overlap_report
+from .timeline import PhaseStat, Timeline, TSpan
 from .trace import Span, Tracer, current_tracer, deep_tracing, tracing
 
 __all__ = [
@@ -34,7 +34,7 @@ __all__ = [
     "live_device_bytes", "MeteredSource",
     "JsonlExporter", "ChromeTraceExporter", "register_exporter",
     "get_exporter", "exporter_names",
-    "Timeline", "TSpan", "PhaseStat", "overlap_report",
+    "Timeline", "TSpan", "PhaseStat",
     "ProgressReporter",
     "TelemetryServer", "prometheus_text", "PrometheusExporter",
 ]

@@ -68,7 +68,7 @@ class JsonlExporter:
     """One JSON object per line.
 
     Line schemas (``type`` discriminates):
-      span    {type, name, ts, dur, depth, index, attrs}
+      span    {type, name, ts, dur, depth, index, parent, attrs}
       event   {type, name, ts, span, attrs}
       metric  {type: "counter"|"gauge"|"histogram", name, ...snapshot}
     ``ts``/``dur`` are seconds from the trace origin.
@@ -83,7 +83,7 @@ class JsonlExporter:
             lines.append({"type": "span", "name": sp.name,
                           "ts": _rebase(tracer, sp.t0), "dur": sp.dur,
                           "depth": sp.depth, "index": sp.index,
-                          "attrs": sp.attrs})
+                          "parent": sp.parent, "attrs": sp.attrs})
             for name, ts, attrs in sp.events:
                 lines.append({"type": "event", "name": name,
                               "ts": None if ts is None
@@ -100,11 +100,11 @@ class ChromeTraceExporter:
     """Chrome trace-event JSON, viewable in Perfetto.
 
     Spans become ``ph:"X"`` complete events (``ts``/``dur`` in
-    microseconds — the format's unit), span events become thread-scoped
-    instants (``ph:"i"``), and every gauge's sample series becomes a
-    ``ph:"C"`` counter track.  All spans share one pid/tid so Perfetto
-    nests them by interval containment, which matches the tracer's
-    stack discipline.
+    microseconds — the format's unit; ``index`` and ``parent`` give the
+    span tree), span events become thread-scoped instants (``ph:"i"``),
+    and every gauge's sample series becomes a ``ph:"C"`` counter track.
+    All spans share one pid/tid so Perfetto nests them by interval
+    containment, which matches the tracer's stack discipline.
     """
 
     PID = 1
@@ -122,6 +122,7 @@ class ChromeTraceExporter:
                        "tid": self.TID,
                        "ts": _rebase(tracer, sp.t0) * us,
                        "dur": 0.0 if sp.dur is None else sp.dur * us,
+                       "index": sp.index, "parent": sp.parent,
                        "args": _jsonable(sp.attrs)})
             for name, ts, attrs in sp.events:
                 ev.append({"ph": "i", "s": "t", "name": name,

@@ -1,4 +1,4 @@
-"""Trace analytics: concurrency timeline, critical path, overlap audit.
+"""Trace analytics: concurrency timeline, critical path, psum overlap.
 
 PR 7 made runs *narrate* themselves (spans, metrics, exporters); this
 module makes the narration *answer questions*.  A :class:`Timeline` is a
@@ -12,17 +12,9 @@ in-process analysis share one code path) — and computes:
   children's — so nested spans are not double-counted and the phase
   table sums to the root's duration.  :meth:`Timeline.critical_path`
   ranks phases by self time: where wall-clock actually went.
-- **overlap efficiency**: host-side spans never overlap each other (the
-  driver loop is single-threaded), so a single trace cannot show how
-  much H2D was hidden under compute.  What *does* differ is span
-  semantics: under ``overlap=False`` the ``stream.accumulate`` span
-  blocks on the device (true device time); under ``overlap=True`` it
-  measures dispatch only, the device work hiding under the next chunk's
-  ``stream.h2d``.  :func:`overlap_report` therefore audits a TRACE PAIR
-  — pipelined vs serialized runs of the same job — and reports the
-  measured hidden fraction; :meth:`Timeline.psum_overlap` reads the
-  per-panel ``qr.panel_schedule`` events directly, since the distributed
-  QR engine records each panel's psum as overlapped or serialized.
+- **psum overlap** (:meth:`Timeline.psum_overlap`): the per-panel
+  ``qr.panel_schedule`` events, since the distributed QR engine records
+  each panel's psum as overlapped or serialized.
 - **throughput** (rows/s, bytes/s, chunks/s) from the stream spans and
   the metric snapshot riding the same trace.
 - **stragglers** (:meth:`Timeline.stragglers`): for each repeated phase,
@@ -40,7 +32,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Optional
 
-__all__ = ["TSpan", "PhaseStat", "Timeline", "overlap_report"]
+__all__ = ["TSpan", "PhaseStat", "Timeline"]
 
 
 @dataclass
@@ -234,49 +226,3 @@ class Timeline:
                 "throughput": self.throughput(),
                 "stragglers": self.stragglers(),
                 "metrics": self.metrics}
-
-
-def _phase_sum(tl: Timeline, name: str) -> float:
-    st = tl.phases().get(name)
-    return st.total if st is not None else 0.0
-
-
-def overlap_report(pipelined: Timeline, serialized: Timeline) -> dict:
-    """Measured H2D-hidden fraction from an ``overlap=True`` /
-    ``overlap=False`` trace pair of the same job.
-
-    In the serialized trace both ``stream.h2d`` and ``stream.accumulate``
-    block on the device, so their summed durations are true exposed
-    time.  In the pipelined trace the accumulate spans are dispatch-only
-    — device GEMMs hide under the next chunk's H2D — so the *drop* in
-    summed exposed time between the two traces is exactly the work the
-    pipeline hid.  Normalizing by the smaller of the two serialized
-    phase totals (an upper bound on what double-buffering CAN hide)
-    gives a fraction in [0, 1]:
-
-        hidden = clamp((exposed_serial − exposed_pipe)
-                       / min(Σ h2d_serial, Σ acc_serial), 0, 1)
-
-    The serialized run's own hidden fraction is 0 by construction; CI
-    gates on ``hidden`` staying above a margin (``benchmarks/
-    bench_overlap.py``) — the dynamic complement to the static
-    ``jaxpr.collective-overlap`` rule.
-    """
-    h2d_s = _phase_sum(serialized, "stream.h2d")
-    acc_s = _phase_sum(serialized, "stream.accumulate")
-    h2d_p = _phase_sum(pipelined, "stream.h2d")
-    acc_p = _phase_sum(pipelined, "stream.accumulate")
-    exposed_s = h2d_s + acc_s
-    exposed_p = h2d_p + acc_p
-    denom = min(h2d_s, acc_s)
-    if denom > 0:
-        hidden = max(0.0, min(1.0, (exposed_s - exposed_p) / denom))
-    else:
-        hidden = 0.0
-    wall_p, wall_s = pipelined.wall(), serialized.wall()
-    return {"h2d_serial_s": h2d_s, "accumulate_serial_s": acc_s,
-            "h2d_pipelined_s": h2d_p, "accumulate_pipelined_s": acc_p,
-            "exposed_serial_s": exposed_s, "exposed_pipelined_s": exposed_p,
-            "hidden_fraction": hidden,
-            "wall_pipelined_s": wall_p, "wall_serialized_s": wall_s,
-            "speedup": wall_s / wall_p if wall_p > 0 else float("inf")}

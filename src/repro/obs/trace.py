@@ -30,6 +30,16 @@ tracer is installed; every helper returns a shared no-op object then):
         acc = sketch_accum(omega_c, cur, acc)
         sp.block_on(acc)                 # close waits for the device
 
+While a tracer is installed every span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+holds the program's spans on the host beside the device's programs, on
+one clock (aligned by the profiler to about a millisecond on a TPU
+v5e): an idle gap on the device can be laid against the host work open
+at that instant.  Each JIT compile, or load from the persistent
+compilation cache, that runs while a tracer is installed is recorded as
+a ``jax.compile`` span (a child of the span that compiled) and counted
+in the counters ``jax.compiles`` and ``jax.compile_s``.
+
 ``deep=True`` additionally switches engines that support it into their
 step-at-a-time profiling schedule (e.g. ``core.qr.pivoted_qr`` runs the
 blocked engine panel-by-panel with a span per panel).  Deep tracing is
@@ -41,20 +51,34 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 from dataclasses import dataclass, field
 from typing import Optional
+
+import jax
 
 from .clock import Clock, MONOTONIC
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 
 __all__ = ["Span", "Tracer", "tracing", "current_tracer", "deep_tracing",
-           "span", "event", "counter", "gauge", "histogram", "attributes"]
+           "span", "event", "counter", "gauge", "histogram", "attributes",
+           "COMPILE_SPAN"]
+
+# What JAX records around every backend compile or persistent-cache load
+# (``jax._src.dispatch.BACKEND_COMPILE_EVENT``), and what a tracer makes
+# of it: one span per compile, a count and a sum of seconds.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_SPAN = "jax.compile"
+COMPILES = "jax.compiles"
+COMPILE_S = "jax.compile_s"
 
 
 @dataclass
 class Span:
     """One timed interval.  ``t1`` is None while the span is open;
-    ``events`` are (name, ts, attrs) points inside the interval."""
+    ``events`` are (name, ts, attrs) points inside the interval;
+    ``parent`` is the ``index`` of the enclosing span (None at the
+    root)."""
     name: str
     t0: float
     depth: int
@@ -63,7 +87,9 @@ class Span:
     t1: Optional[float] = None
     attrs: dict = field(default_factory=dict)
     events: list = field(default_factory=list)
+    parent: Optional[int] = None
     _pending: list = field(default_factory=list, repr=False)
+    _annotation: object = field(default=None, repr=False)
 
     @property
     def dur(self) -> Optional[float]:
@@ -120,6 +146,30 @@ def _null_span_cm():
     yield NULL_SPAN
 
 
+def _leave(sp: Span) -> None:
+    """End ``sp``'s profiler annotation."""
+    if sp._annotation is not None:
+        sp._annotation.__exit__(None, None, None)
+        sp._annotation = None
+
+
+@functools.cache
+def _listen_for_compiles() -> None:
+    """Register, once per process, the listener that hands each compile
+    to the ambient tracer.  JAX fires it only when it compiles or loads
+    from its cache, never on a warm call, so it costs the hot path
+    nothing; with no tracer installed it returns at once."""
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def _on_duration(event: str, seconds: float, **attrs) -> None:
+    if event != COMPILE_EVENT:
+        return
+    tr = _CURRENT.get()
+    if tr is not None:
+        tr.compiled(seconds, program=attrs.get("fun_name"))
+
+
 class Tracer:
     """Span recorder + metrics registry + exporter fan-out.
 
@@ -141,6 +191,7 @@ class Tracer:
         self._n = 0
         self._defaults: list[dict] = []      # bind() attribute stack
         self.t_origin: Optional[float] = None
+        _listen_for_compiles()
 
     # ------------------------------------------------------------- spans
     @contextlib.contextmanager
@@ -156,7 +207,15 @@ class Tracer:
             self._defaults.pop()
 
     def start(self, name: str, **attrs) -> Span:
-        t0 = self.clock()
+        sp = self._new_span(name, self.clock(), attrs)
+        self._stack.append(sp)
+        sp._annotation = jax.profiler.TraceAnnotation(name)
+        sp._annotation.__enter__()
+        return sp
+
+    def _new_span(self, name: str, t0: float, attrs: dict) -> Span:
+        """A span under the one open now, with the bound default attrs
+        (explicit ``attrs`` win)."""
         if self.t_origin is None:
             self.t_origin = t0
         merged: dict = {}
@@ -164,14 +223,13 @@ class Tracer:
             merged.update(d)
         merged.update(attrs)
         sp = Span(name=name, t0=t0, depth=len(self._stack), index=self._n,
-                  attrs=merged)
+                  attrs=merged,
+                  parent=self._stack[-1].index if self._stack else None)
         self._n += 1
-        self._stack.append(sp)
         return sp
 
     def end(self, sp: Span) -> Span:
         if sp._pending:
-            import jax
             jax.block_until_ready(sp._pending)
             sp._pending = []
         sp.t1 = self.clock()
@@ -183,8 +241,22 @@ class Tracer:
                 break
             top.t1 = sp.t1
             top.attrs.setdefault("error", "span leaked (closed by child)")
+            _leave(top)
             self.spans.append(top)
+        _leave(sp)
         self.spans.append(sp)
+        return sp
+
+    def compiled(self, seconds: float, **attrs) -> Span:
+        """Record a compile (or persistent-cache load) of ``seconds`` that
+        has just ended: a closed ``jax.compile`` span, a child of the span
+        open now, and the counters ``jax.compiles`` and ``jax.compile_s``."""
+        t1 = self.clock()
+        sp = self._new_span(COMPILE_SPAN, t1 - seconds, attrs)
+        sp.t1 = t1
+        self.spans.append(sp)
+        self.counter(COMPILES).add(1)
+        self.counter(COMPILE_S).add(seconds)
         return sp
 
     @contextlib.contextmanager
@@ -205,9 +277,8 @@ class Tracer:
         if self._stack:
             self._stack[-1].event(name, ts=ts, **attrs)
         else:
-            sp = self.start(name, **attrs)
-            sp.t0 = sp.t1 = ts           # zero-length at the single read
-            self._stack.pop()
+            sp = self._new_span(name, ts, attrs)
+            sp.t1 = ts                   # zero-length at the single read
             self.spans.append(sp)
 
     # ------------------------------------------------------------ metrics

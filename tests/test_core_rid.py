@@ -245,3 +245,66 @@ def test_main_path_matmuls_at_full_precision(name):
     highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
     assert all(e.params["precision"] == highest for e in dots), \
         {e.params["precision"] for e in dots}
+
+
+# ------------------------------------------------------------ tracing
+
+@pytest.mark.parametrize("kind,cplx,dtype", [
+    ("srft", True, jnp.complex64), ("gaussian", False, jnp.float32)])
+def test_rid_bits_unchanged_by_tracing(kind, cplx, dtype):
+    """The spans time the host's dispatch of each stage and change no
+    program: ``B``, ``P`` and ``J`` are bit-identical under a tracer."""
+    from repro.obs import tracing
+    A = lowrank(jax.random.key(3), 96, 80, 6, dtype=jnp.float32,
+                cplx=cplx).astype(dtype)
+    base = rid(jax.random.key(4), A, 6, sketch_kind=kind)
+    with tracing():
+        traced = rid(jax.random.key(4), A, 6, sketch_kind=kind)
+    for name in ("B", "P", "J"):
+        np.testing.assert_array_equal(np.asarray(getattr(base, name)),
+                                      np.asarray(getattr(traced, name)))
+
+
+def test_rid_span_tree():
+    """``rid`` > ``rid.sketch`` / ``rid.qr_interp`` / ``rid.gather``, the
+    root carrying the shape; ``rid_from_sketch`` alone opens its two."""
+    from repro.core import rid_from_sketch
+    from repro.obs import tracing
+    A = lowrank(jax.random.key(5), 64, 48, 4, dtype=jnp.float32)
+    with tracing() as tr:
+        rid(jax.random.key(6), A, 4, sketch_kind="gaussian")
+    spans = [s for s in sorted(tr.spans, key=lambda s: s.index)
+             if s.name != "jax.compile"]
+    root, *children = spans
+    assert (root.name, root.parent, root.depth) == ("rid", None, 0)
+    assert root.attrs == {"m": 64, "n": 48, "k": 4, "l": 8,
+                          "sketch_kind": "gaussian"}
+    assert [(s.name, s.parent, s.depth) for s in children] == [
+        ("rid.sketch", root.index, 1), ("rid.qr_interp", root.index, 1),
+        ("rid.gather", root.index, 1)]
+    assert all(root.t0 <= s.t0 and s.t1 <= root.t1 for s in children)
+    Y = gaussian_sketch(jax.random.key(6), A, 8)
+    with tracing() as tr:
+        rid_from_sketch(A, Y, 4)
+    assert [(s.name, s.parent) for s in sorted(tr.spans,
+                                               key=lambda s: s.index)
+            if s.name != "jax.compile"] == [
+        ("rid.qr_interp", None), ("rid.gather", None)]
+
+
+def test_rid_under_caller_jit_records_no_span():
+    """Inside a caller's jit ``A`` is a tracer: a span would time the
+    trace, so none opens; the caller's compile is all the tracer sees."""
+    from repro.obs import tracing
+    A = lowrank(jax.random.key(9), 64, 48, 4, dtype=jnp.float32)
+
+    @jax.jit
+    def caller(A):
+        return rid(jax.random.key(1), A, 4, sketch_kind="gaussian").J
+
+    with tracing() as tr:
+        J = caller(A)
+    assert {s.name for s in tr.spans} == {"jax.compile"}
+    np.testing.assert_array_equal(
+        np.asarray(J),
+        np.asarray(rid(jax.random.key(1), A, 4, sketch_kind="gaussian").J))

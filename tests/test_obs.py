@@ -233,6 +233,95 @@ def test_exporter_registry_roundtrip(tmp_path):
         register_exporter("chrome")(object)
 
 
+def test_span_parent_recorded_and_exported(tmp_path):
+    """Each span names the span that caused it: ``parent`` is the
+    enclosing span's index (None at the root), in both exporters."""
+    tr = _tiny_trace()
+    tr.event("orphan")                           # root-level instant span
+    outer, inner, orphan = sorted(tr.spans, key=lambda s: s.index)
+    assert (outer.parent, inner.parent, orphan.parent) == (None, 0, None)
+    JsonlExporter(tmp_path / "t.jsonl").export(tr)
+    lines = [json.loads(x) for x in
+             (tmp_path / "t.jsonl").read_text().splitlines()]
+    assert [(l["name"], l["parent"]) for l in lines
+            if l["type"] == "span"] == [("outer", None), ("inner", 0),
+                                        ("orphan", None)]
+    ChromeTraceExporter(tmp_path / "t.json").export(tr)
+    ev = json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+    assert [(e["name"], e["index"], e["parent"]) for e in ev
+            if e["ph"] == "X"] == [("outer", 0, None), ("inner", 1, 0),
+                                   ("orphan", 2, None)]
+
+
+def _host_events(log_dir):
+    """``(name, start_ns, end_ns)`` of every event on the profiler's host
+    plane, from the one ``.xplane.pb`` under ``log_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = log_dir.glob("**/*.xplane.pb")
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_spans_on_the_profiler_host_plane(tmp_path, traced):
+    """With a tracer installed every span is also a profiler annotation on
+    ``/host:CPU``, on the device trace's clock and nested as the spans
+    are, including spans left open and closed by a child or by
+    ``finish()``; with none installed nothing is annotated."""
+    from repro.core import rid
+    A = jnp.asarray(np.random.default_rng(5).standard_normal((64, 48)),
+                    jnp.float32)
+    rid(jax.random.key(2), A, 4)                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        if traced:
+            with tracing() as tr:
+                rid(jax.random.key(2), A, 4)
+                leaked = tr.start("obs.leaked")
+                tr.start("obs.leaked_child")
+                tr.end(leaked)
+                tr.start("obs.dangling")
+        else:
+            rid(jax.random.key(2), A, 4)
+    finally:
+        jax.profiler.stop_trace()
+    ours = {name: (t0, t1) for name, t0, t1 in _host_events(tmp_path)
+            if name.startswith(("rid", "obs."))}
+    if not traced:
+        assert ours == {}
+        return
+    assert set(ours) == {"rid", "rid.sketch", "rid.qr_interp", "rid.gather",
+                         "obs.leaked", "obs.leaked_child", "obs.dangling"}
+    r0, r1 = ours["rid"]
+    for child in ("rid.sketch", "rid.qr_interp", "rid.gather"):
+        assert r0 <= ours[child][0] <= ours[child][1] <= r1
+    assert ours["rid.sketch"][1] <= ours["rid.qr_interp"][0]
+    assert ours["obs.leaked"][0] <= ours["obs.leaked_child"][0]
+
+
+def test_compile_counted_once_under_tracer():
+    """A fresh jit compiles once: one ``jax.compile`` span under the span
+    that compiled, ``jax.compiles`` 1 and ``jax.compile_s`` its seconds;
+    the warm second call counts 0."""
+    f = jax.jit(lambda x: x * 3 - 1)
+    x = jnp.ones(5)
+    with tracing() as tr:
+        with obs_trace.span("first") as first:
+            f(x)
+    (c,) = [s for s in tr.spans if s.name == "jax.compile"]
+    assert c.parent == first.index and c.attrs["program"] == "jit(<lambda>)"
+    assert tr.metrics.counter("jax.compiles").value == 1
+    assert tr.metrics.counter("jax.compile_s").value == c.dur > 0
+    assert first.t0 <= c.t0 <= c.t1 <= first.t1
+    with tracing() as tr:
+        f(x)
+    assert tr.metrics.counter("jax.compiles").value == 0
+    assert tr.spans == []
+    jax.jit(lambda x: x + 7)(x)                  # no tracer: nothing to count
+
+
 # ------------------------------------- observer effect: engines under trace
 
 def test_rid_streamed_bits_unchanged_by_tracing():
@@ -303,6 +392,8 @@ def test_jitted_caller_skips_spans():
     with tracing(deep=True) as tr:
         Q, piv, R = inner(Y)
     jax.block_until_ready(Q)
-    assert [s.name for s in tr.spans] == []      # no trace-time spans
+    # no trace-time spans: the one span is the caller's compile
+    assert [(s.name, s.attrs["program"]) for s in tr.spans] == [
+        ("jax.compile", "jit(inner)")]
     Q0, piv0, R0 = pivoted_qr(Y, 8, impl="blocked", panel=8)
     np.testing.assert_array_equal(np.asarray(piv), np.asarray(piv0))

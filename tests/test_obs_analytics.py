@@ -1,8 +1,8 @@
 """Trace analytics, live progress, and telemetry (ISSUE 10).
 
 Every timing-sensitive contract runs on a FakeClock against SYNTHETIC
-traces with known overlap, so efficiency fractions, critical paths, and
-ETAs are asserted as exact arithmetic, not tolerances.  The last block
+traces, so psum-overlap fractions, critical paths, and ETAs are
+asserted as exact arithmetic, not tolerances.  The last block
 re-pins the observer-effect contract for the newly instrumented paths:
 progress reporting + tracing never change a decomposition's bits.
 """
@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.obs import (FakeClock, ProgressReporter, Timeline, Tracer,
-                       overlap_report, prometheus_text, tracing)
+                       prometheus_text, tracing)
 from repro.obs import trace as obs_trace
 from repro.obs.export import exporter_names, get_exporter
 from repro.obs.metrics import MetricsRegistry
@@ -30,9 +30,7 @@ KEY = jax.random.key(0)
 def _stream_trace(acc_dur: float, *, h2d_dur: float = 1.0, chunks: int = 2,
                   job: str = "job0") -> Tracer:
     """A synthetic pass-1 trace: per chunk, one h2d span of ``h2d_dur``
-    and one accumulate span of ``acc_dur`` (the serialized/pipelined
-    distinction is exactly the accumulate duration: blocked device time
-    vs dispatch-only)."""
+    and one accumulate span of ``acc_dur``."""
     clk = FakeClock(100.0)
     tr = Tracer(clock=clk)
     with tr.bind(job=job):
@@ -48,31 +46,6 @@ def _stream_trace(acc_dur: float, *, h2d_dur: float = 1.0, chunks: int = 2,
 
 
 # ----------------------------------------------------------------- timeline
-
-def test_overlap_report_exact_hidden_fraction():
-    """2 chunks, h2d=1s each; accumulate blocks 1s serialized but
-    dispatches in 0.25s pipelined: exposed drops from 4s to 2.5s, the
-    hideable budget is min(2, 2)=2s, so hidden = 1.5/2 = 0.75 exactly."""
-    ser = Timeline.from_tracer(_stream_trace(1.0))
-    pip = Timeline.from_tracer(_stream_trace(0.25))
-    rep = overlap_report(pip, ser)
-    assert rep["hidden_fraction"] == 0.75
-    assert rep["exposed_serial_s"] == 4.0
-    assert rep["exposed_pipelined_s"] == 2.5
-    assert rep["wall_serialized_s"] == 4.0 and rep["wall_pipelined_s"] == 2.5
-    assert rep["speedup"] == 4.0 / 2.5
-    # the serialized trace audited against itself hides nothing
-    assert overlap_report(ser, ser)["hidden_fraction"] == 0.0
-
-
-def test_overlap_report_clamps_and_degenerate():
-    ser = Timeline.from_tracer(_stream_trace(1.0))
-    # a pipelined trace cheaper than physically possible clamps to 1.0
-    pip = Timeline.from_tracer(_stream_trace(0.0, h2d_dur=0.0))
-    assert overlap_report(pip, ser)["hidden_fraction"] == 1.0
-    empty = Timeline([])
-    assert overlap_report(empty, empty)["hidden_fraction"] == 0.0
-
 
 def test_critical_path_uses_self_time_no_double_count():
     """Nested spans must not double-count: the parent's contribution is

@@ -1,7 +1,8 @@
 """Randomized interpolative decomposition (the paper's core algorithm).
 
 Pipeline (paper section 2):                      cost (paper's accounting)
-  1. sketch      Y = Phi A          (l x n)      O(mn log m)   [FFT backend]
+  1. sketch      Y = Phi A          (l x n)      O(lmn)        [srft GEMM, small l]
+                                                 O(mn log m)   [srft FFT, large l]
   2. pivoted QR  Y Pi ~= Q [R1 R2]               O(l k n)      [the bottleneck]
   3. interp      R1 T = R2, P = [I T] Pi^-1      O(k(l+k)(n-k)) [column-parallel]
   4. subset      B = A[:, J]
@@ -29,10 +30,12 @@ Step 2 has two engines, selected by ``qr_impl``:
 
 OBSERVABILITY: under a ``repro.obs`` tracer, ``rid`` opens the span
 ``rid`` (attrs ``m``, ``n``, ``k``, ``l``, ``sketch_kind``) with the
-children ``rid.sketch``, ``rid.qr_interp`` and ``rid.gather`` (the last
-two also from ``rid_from_sketch`` called directly).  They time the
-host's dispatch of each stage and never block, so the schedule is the
-untraced one; inside a caller's jit no span opens.
+children ``rid.sketch`` (for srft, the attr ``srft_path``: ``"dense"``
+or ``"fft"``, as ``core.sketch.srft_path`` picks), ``rid.qr_interp`` and
+``rid.gather`` (the last two also from ``rid_from_sketch`` called
+directly).  They time the host's dispatch of each stage and never
+block, so the schedule is the untraced one; inside a caller's jit no
+span opens.
 """
 from __future__ import annotations
 
@@ -46,7 +49,7 @@ import jax.numpy as jnp
 from ..kernels.common import full_precision
 from ..obs import trace as obs_trace
 from .qr import pivoted_qr
-from .sketch import sketch
+from .sketch import sketch, srft_path
 from .tsolve import interp_from_qr
 from .types import IDResult
 from .validate import check_l_ge_k
@@ -126,7 +129,9 @@ def rid(key: jax.Array, A: jax.Array, k: int, *, l: Optional[int] = None,
     check_l_ge_k(l, k)
     m, n = A.shape
     with _span(A, "rid", m=m, n=n, k=k, l=l, sketch_kind=sketch_kind):
-        with _span(A, "rid.sketch"):
+        attrs = ({"srft_path": srft_path(l, A.dtype)}
+                 if sketch_kind == "srft" else {})
+        with _span(A, "rid.sketch", **attrs):
             Y = sketch(key, A, l, kind=sketch_kind).Y
         return rid_from_sketch(A, Y, k, qr_impl=qr_impl, qr_panel=qr_panel,
                                qr_norm_recompute=qr_norm_recompute)

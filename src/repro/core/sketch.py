@@ -4,7 +4,10 @@ Three interchangeable backends (paper section 2 + DESIGN.md section 2):
 
 * ``srft``     — the paper's faithful operator ``Y = S F D A`` (eq. 4-7):
                  random complex phases per row, column-wise DFT, and
-                 ``l`` i.i.d. uniformly sampled rows.
+                 ``l`` i.i.d. uniformly sampled rows.  For small ``l``
+                 the ``l`` sampled rows of ``F D`` are applied as one
+                 dense GEMM, O(lmn) on the MXU; for large ``l`` as the
+                 full FFT, O(mn log m) (``srft_path``).
 * ``srht``     — real-valued TPU-native analogue: random signs, a fast
                  Walsh-Hadamard transform (power-of-two butterflies that
                  block cleanly into VMEM — see ``repro.kernels.srht``),
@@ -50,6 +53,7 @@ from .types import SketchResult
 __all__ = [
     "sketch",
     "srft_sketch",
+    "srft_path",
     "srht_sketch",
     "gaussian_sketch",
     "gaussian_omega_cols",
@@ -88,13 +92,84 @@ def _sample_rows(key: jax.Array, m: int, l: int) -> jax.Array:
     return jax.random.randint(key, (l,), 0, m, dtype=jnp.int32)
 
 
+# ``srft_sketch`` applies ``S F D`` as a dense GEMM while ``l`` times the
+# number of real planes of ``A`` (2 if complex, 1 if real) is at most this,
+# and as a full FFT above it: the GEMM's cost grows with ``l`` and the
+# FFT's does not.  On a TPU v5e at m = n = 2^14 the two cross near
+# l = 740 for complex64 and l = 1460 for float32 (PERF.md, section 6).
+SRFT_DENSE_MAX_PLANE_ROWS = 1400
+
+
+def srft_path(l: int, dtype) -> str:
+    """How ``srft_sketch`` applies its operator for ``l`` sampled rows of an
+    ``A`` of ``dtype``: ``"dense"`` (the ``l`` sampled DFT rows as one
+    GEMM) or ``"fft"`` (the full transform, then the ``l`` rows)."""
+    planes = 2 if jnp.issubdtype(dtype, jnp.complexfloating) else 1
+    return "dense" if l * planes <= SRFT_DENSE_MAX_PLANE_ROWS else "fft"
+
+
+def _mulmod(a: jax.Array, b: jax.Array, m: int) -> jax.Array:
+    """``(a * b) mod m`` for int32 ``a``, ``b`` in ``[0, m)``, exact in 32-bit
+    integers for every ``m <= 2^29``: Horner over ``s``-bit digits of ``b``,
+    with ``m * 2^s <= 2^30`` so that no partial sum passes 2^31."""
+    s = max(1, 30 - (m - 1).bit_length())
+    digits = max(1, -(-(m - 1).bit_length() // s))
+    acc = jnp.zeros(jnp.broadcast_shapes(a.shape, b.shape), jnp.int32)
+    for t in reversed(range(digits)):
+        digit = (b >> (s * t)) & ((1 << s) - 1)
+        acc = ((acc << s) + a * digit) % m
+    return acc
+
+
+def _srft_operator(d: jax.Array, rows: jax.Array, l: int) -> jax.Array:
+    """``S F D`` as an ``l x m`` matrix, scaled by ``1/sqrt(l)``:
+    ``W[j, i] = d_i exp(-2 pi i (rows_j i mod m) / m) / sqrt(l)``.  The
+    twiddle index is reduced mod ``m`` in integers before it becomes an
+    angle, so it stays exact where ``rows_j * i`` passes 2^31."""
+    m = d.shape[0]
+    rdtype = jnp.finfo(d.dtype).dtype
+    t = _mulmod(rows.astype(jnp.int32)[:, None],
+                jnp.arange(m, dtype=jnp.int32)[None, :], m)
+    angle = t.astype(rdtype) * jnp.asarray(-2 * math.pi / m, rdtype)
+    twiddle = jax.lax.complex(jnp.cos(angle), jnp.sin(angle))
+    return twiddle * (d * jnp.asarray(1 / math.sqrt(l), rdtype))[None, :]
+
+
+def _srft_dense(d: jax.Array, rows: jax.Array, A: jax.Array,
+                l: int) -> jax.Array:
+    """``Y = W A`` with ``W = _srft_operator(d, rows, l)``, as real GEMMs at
+    ``HIGHEST``: ``[Wr; Wi]`` (2l x m) against each real plane of ``A``."""
+    W = _srft_operator(d, rows, l)
+    rdtype = W.real.dtype
+    Wc = jnp.concatenate([W.real, W.imag], axis=0)
+    dot = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    if jnp.issubdtype(A.dtype, jnp.complexfloating):
+        P = dot(Wc, A.real.astype(rdtype))
+        Q = dot(Wc, A.imag.astype(rdtype))
+        return jax.lax.complex(P[:l] - Q[l:], Q[:l] + P[l:])
+    P = dot(Wc, A.astype(rdtype))
+    return jax.lax.complex(P[:l], P[l:])
+
+
+def _srft_fft(d: jax.Array, rows: jax.Array, A: jax.Array,
+              l: int) -> jax.Array:
+    """``Y = S F D A`` through the full column-wise FFT of ``D A``."""
+    m = d.shape[0]
+    FDA = jnp.fft.fft(d[:, None] * A.astype(d.dtype), axis=0)
+    scale = jnp.asarray(1.0 / math.sqrt(l * m) * math.sqrt(m), dtype=d.dtype)  # = 1/sqrt(l)
+    return FDA[rows] * scale
+
+
 @partial(jax.jit, static_argnames=("l",))
 def srft_sketch(key: jax.Array, A: jax.Array, l: int) -> jax.Array:
     """Paper eq. (4): ``Y = S F D A`` — the subsampled random Fourier transform.
 
     ``D`` multiplies each row by a random unit phase (eq. 7), ``F`` is the
     unnormalized DFT applied to every column (eq. 6), ``S`` keeps ``l``
-    random rows (eq. 5).  Output is complex regardless of input dtype.
+    random rows (eq. 5); the result is scaled by ``1/sqrt(l)``.  Output is
+    complex regardless of input dtype.  ``srft_path`` picks the form:
+    the ``l`` sampled rows of ``F D`` as one dense GEMM, O(lmn) on the
+    MXU, or the full FFT, O(mn log m), for large ``l``.
     """
     m = A.shape[0]
     kphase, krows = jax.random.split(key)
@@ -102,11 +177,9 @@ def srft_sketch(key: jax.Array, A: jax.Array, l: int) -> jax.Array:
     rdtype = jnp.finfo(cdtype).dtype  # float64 for c128, float32 for c64
     phi = jax.random.uniform(kphase, (m,), dtype=rdtype)
     d = jnp.exp((2j * jnp.pi) * phi).astype(cdtype)
-    DA = d[:, None] * A.astype(cdtype)
-    FDA = jnp.fft.fft(DA, axis=0)
     rows = _sample_rows(krows, m, l)
-    scale = jnp.asarray(1.0 / math.sqrt(l * m) * math.sqrt(m), dtype=cdtype)  # = 1/sqrt(l)
-    return FDA[rows] * scale
+    apply = _srft_dense if srft_path(l, A.dtype) == "dense" else _srft_fft
+    return apply(d, rows, A, l)
 
 
 @partial(jax.jit, static_argnames=("l",))

@@ -74,6 +74,74 @@ def test_sketch_preserves_rank(kind, cplx):
     assert float(s[k] / s[0]) < 1e-10        # and not more than k
 
 
+def _srft_draws(key, m, l):
+    """``srft_sketch``'s ``D`` and ``S`` from ``key``, drawn here as the
+    paper's eq. (5) and (7) say, independently of the module's code."""
+    kphase, krows = jax.random.split(key)
+    phi = jax.random.uniform(kphase, (m,), dtype=jnp.float64)
+    d = jnp.exp((2j * jnp.pi) * phi)
+    rows = jax.random.randint(krows, (l,), 0, m, dtype=jnp.int32)
+    return d, rows
+
+
+@pytest.mark.parametrize("m", [256, 300])
+@pytest.mark.parametrize("cplx", [True, False])
+@pytest.mark.parametrize("path", ["dense", "fft"])
+def test_srft_paths_match_explicit_fft(path, cplx, m):
+    """Each way of applying ``S F D`` is the explicit transform:
+    ``fft(d[:, None] * A, axis=0)[rows] / sqrt(l)`` from the same keys,
+    for complex and real ``A`` and an ``m`` that is not a power of two;
+    ``srft_sketch`` itself takes the path ``srft_path`` picks."""
+    from repro.core.sketch import _srft_dense, _srft_fft, srft_path
+    key, l = jax.random.key(21), 24
+    A = lowrank(jax.random.key(22), m, 40, 6, cplx=cplx)
+    d, rows = _srft_draws(key, m, l)
+    ref = np.fft.fft(np.asarray(d)[:, None] * np.asarray(A),
+                     axis=0)[np.asarray(rows)] / np.sqrt(l)
+    apply = {"dense": _srft_dense, "fft": _srft_fft}[path]
+    Y = apply(d, rows, A, l)
+    assert Y.dtype == jnp.complex128
+    scale = np.abs(ref).max()
+    np.testing.assert_allclose(np.asarray(Y), ref, atol=1e-10 * scale)
+    if srft_path(l, A.dtype) == path:
+        np.testing.assert_allclose(np.asarray(srft_sketch(key, A, l)), ref,
+                                   atol=1e-10 * scale)
+
+
+@pytest.mark.parametrize("m", [2 ** 18, 46349])
+def test_srft_operator_twiddle_index_past_int32(m):
+    """The dense operator's twiddle index ``rows_j * i mod m`` is reduced in
+    32-bit integers where ``rows_j * i`` passes 2^31 (m > 46340): rows of
+    ``W`` at the grid's largest m and at an odd m, against int64 and
+    float64 in numpy."""
+    from repro.core.sketch import _mulmod, _srft_operator
+    l = 3
+    rows = jnp.array([m - 1, m // 2 + 1, 40503], jnp.int32)
+    i = jnp.arange(m, dtype=jnp.int32)
+    t = (np.asarray(rows, np.int64)[:, None] * np.arange(m)) % m
+    assert t.max() == m - 1 and int(rows[0]) * (m - 1) >= 2 ** 31
+    np.testing.assert_array_equal(
+        np.asarray(_mulmod(rows[:, None], i[None, :], m)), t)
+    d, _ = _srft_draws(jax.random.key(23), m, l)
+    ref = (np.exp(-2j * np.pi * t / m) * np.asarray(d)[None, :]
+           / np.sqrt(l))
+    np.testing.assert_allclose(np.asarray(_srft_operator(d, rows, l)), ref,
+                               atol=1e-12)
+
+
+@pytest.mark.parametrize("l,dtype,path", [
+    (200, jnp.complex64, "dense"), (800, jnp.complex64, "fft"),
+    (2000, jnp.complex64, "fft"), (1200, jnp.float32, "dense"),
+    (1600, jnp.float32, "fft")])
+def test_srft_path_rule(l, dtype, path):
+    """The shape rule at m = 2^14: the paper grid's l = 200 (rows 1-2)
+    takes the GEMM; its l = 800 (row 3) and 2000 (rows 6 and 8) keep the
+    FFT; a real ``A`` halves the GEMM and moves the crossover to twice the
+    l (the measured table in PERF.md)."""
+    from repro.core.sketch import srft_path
+    assert srft_path(l, dtype) == path
+
+
 def test_fwht_orthonormal():
     key = jax.random.key(2)
     x = jax.random.normal(key, (256, 33), dtype=jnp.float64)
@@ -216,6 +284,10 @@ def _stage(name):
     f32 = jnp.float32
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     return {
+        "srft_sketch_c64": (lambda key, A: srft_sketch(key, A, 24),
+                            (jax.random.key(0), S((256, 400), jnp.complex64))),
+        "srft_sketch_f32": (lambda key, A: srft_sketch(key, A, 24),
+                            (jax.random.key(0), S((256, 400), f32))),
         "sketch_accum": (sketch_accum, (S((48, 256), f32), S((256, 400), f32))),
         "qr_interp": (lambda Y: _qr_interp(Y, 21, "blocked", 7, "auto"),
                       (S((48, 400), f32),)),
@@ -229,7 +301,8 @@ def _stage(name):
     }[name]
 
 
-@pytest.mark.parametrize("name", ["sketch_accum", "qr_interp",
+@pytest.mark.parametrize("name", ["srft_sketch_c64", "srft_sketch_f32",
+                                  "sketch_accum", "qr_interp",
                                   "sharded_qr_interp", "rid_distributed"])
 def test_main_path_matmuls_at_full_precision(name):
     """Every matmul a stage traces — XLA's and the Pallas kernels' —
@@ -267,8 +340,10 @@ def test_rid_bits_unchanged_by_tracing(kind, cplx, dtype):
 
 def test_rid_span_tree():
     """``rid`` > ``rid.sketch`` / ``rid.qr_interp`` / ``rid.gather``, the
-    root carrying the shape; ``rid_from_sketch`` alone opens its two."""
+    root carrying the shape and ``rid.sketch``, for srft, the path
+    ``srft_path`` picks; ``rid_from_sketch`` alone opens its two."""
     from repro.core import rid_from_sketch
+    from repro.core.sketch import SRFT_DENSE_MAX_PLANE_ROWS
     from repro.obs import tracing
     A = lowrank(jax.random.key(5), 64, 48, 4, dtype=jnp.float32)
     with tracing() as tr:
@@ -283,6 +358,12 @@ def test_rid_span_tree():
         ("rid.sketch", root.index, 1), ("rid.qr_interp", root.index, 1),
         ("rid.gather", root.index, 1)]
     assert all(root.t0 <= s.t0 and s.t1 <= root.t1 for s in children)
+    assert children[0].attrs == {}
+    for l, path in ((8, "dense"), (SRFT_DENSE_MAX_PLANE_ROWS + 1, "fft")):
+        with tracing() as tr:
+            rid(jax.random.key(6), A, 4, l=l, sketch_kind="srft")
+        assert [s.attrs for s in tr.spans if s.name == "rid.sketch"] == [
+            {"srft_path": path}]
     Y = gaussian_sketch(jax.random.key(6), A, 8)
     with tracing() as tr:
         rid_from_sketch(A, Y, 4)

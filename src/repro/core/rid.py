@@ -5,7 +5,8 @@ Pipeline (paper section 2):                      cost (paper's accounting)
                                                  O(mn log m)   [srft FFT, large l]
   2. pivoted QR  Y Pi ~= Q [R1 R2]               O(l k n)      [the bottleneck]
   3. interp      R1 T = R2, P = [I T] Pi^-1      O(k(l+k)(n-k)) [column-parallel]
-  4. subset      B = A[:, J]
+  4. subset      B = A[:, J]                     O(mk)         [k column copies, k <= n/128]
+                                                 O(mn)         [jnp.take, k > n/128]
 
 ``rid`` is jit-compatible (k, l static).  Every stage takes an explicit
 PRNG key; the same key reproduces the same decomposition bit-for-bit,
@@ -32,10 +33,11 @@ OBSERVABILITY: under a ``repro.obs`` tracer, ``rid`` opens the span
 ``rid`` (attrs ``m``, ``n``, ``k``, ``l``, ``sketch_kind``) with the
 children ``rid.sketch`` (for srft, the attr ``srft_path``: ``"dense"``
 or ``"fft"``, as ``core.sketch.srft_path`` picks), ``rid.qr_interp`` and
-``rid.gather`` (the last two also from ``rid_from_sketch`` called
-directly).  They time the host's dispatch of each stage and never
-block, so the schedule is the untraced one; inside a caller's jit no
-span opens.
+``rid.gather`` (the attr ``gather_path``, ``"slices"`` or ``"take"``, as
+``gather_path`` picks; the last two spans also from
+``rid_from_sketch`` called directly).  They time the host's dispatch of
+each stage and never block, so the schedule is the untraced one; inside
+a caller's jit no span opens.
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..kernels.common import full_precision
 from ..obs import trace as obs_trace
@@ -54,7 +57,7 @@ from .tsolve import interp_from_qr
 from .types import IDResult
 from .validate import check_l_ge_k
 
-__all__ = ["rid", "rid_from_sketch"]
+__all__ = ["gather_path", "rid", "rid_from_sketch"]
 
 
 @partial(jax.jit, static_argnames=("k", "qr_impl", "qr_panel",
@@ -91,6 +94,44 @@ def _span(A, name: str, **attrs):
     return obs_trace.span(name, **attrs)
 
 
+# ``_take`` copies the k pivot columns one at a time while k is at most
+# this share of n, and gathers them with one ``jnp.take`` above it.  Each
+# copy reads the column's whole 128-lane tile stripe of ``A`` (23 us per
+# f32 plane at m = 2^14); the take relays all of ``A`` out once (3.2 ms
+# for f32 at m = n = 2^14).  Both grow with m, so the share decides: on a
+# TPU v5e at m = n = 2^14 they cross near k = 142 for float32 and 161 for
+# complex64, whose 32-bit split of ``A`` both forms pay (PERF.md,
+# section 6).
+GATHER_SLICES_MAX_SHARE = 1 / 128
+
+
+def gather_path(k: int, n: int) -> str:
+    """How ``_take`` gathers ``k`` of ``n`` columns: ``"slices"`` (one
+    column copy per pivot) or ``"take"`` (``jnp.take``)."""
+    return "slices" if k <= GATHER_SLICES_MAX_SHARE * n else "take"
+
+
+@jax.jit
+def _take(A: jax.Array, piv: jax.Array) -> jax.Array:
+    """Step 4, ``B = A[:, piv]``, by ``gather_path``.  The slices are one
+    column of ``A`` per step of a loop, so the device moves k stripes of
+    ``A``, not all of it (``jnp.take`` relays the whole matrix out to
+    gather its minor axis).  The loop is not unrolled, so the program is
+    the same size at every ``k``; ``piv`` stays on the device.  On a TPU a
+    complex64 ``A`` is still split whole into its two 32-bit planes as the
+    program reads it: XLA's 64-bit rewrite does that for any op on ``A``."""
+    def column(i, B):
+        j = lax.dynamic_index_in_dim(piv, i, keepdims=False)
+        return lax.dynamic_update_slice_in_dim(
+            B, lax.dynamic_slice_in_dim(A, j, 1, axis=1), i, axis=1)
+
+    m, n = A.shape
+    k = piv.shape[0]
+    if gather_path(k, n) == "take":
+        return jnp.take(A, piv, axis=1)
+    return lax.fori_loop(0, k, column, jnp.zeros((m, k), A.dtype))
+
+
 def rid_from_sketch(A: jax.Array, Y: jax.Array, k: int, *,
                     qr_impl: str = "blocked", qr_panel: int = 32,
                     qr_norm_recompute="auto") -> IDResult:
@@ -98,8 +139,9 @@ def rid_from_sketch(A: jax.Array, Y: jax.Array, k: int, *,
     with _span(A, "rid.qr_interp"):
         P, piv, Q, R = _qr_interp(Y, k, qr_impl, qr_panel,
                                   qr_norm_recompute)
-    with _span(A, "rid.gather"):
-        B = jnp.take(A, piv, axis=1)
+    with _span(A, "rid.gather",
+               gather_path=gather_path(piv.shape[0], A.shape[1])):
+        B = _take(A, piv)
     return IDResult(B=B, P=_cast_interp(P, A.dtype), J=piv, Q=Q, R=R)
 
 

@@ -24,26 +24,29 @@ class PrefetchIterator:
         self._stop = threading.Event()
         self._closed = False
 
+        def put(x) -> bool:
+            """Bounded put that re-checks stop: close() drains the queue,
+            so a blocked put wakes within one timeout.  False if stopped."""
+            while not self._stop.is_set():
+                try:
+                    self._q.put(x, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
+            return False
+
         def worker():
             try:
                 for item in it:
-                    # Bounded put that re-checks stop: close() drains the
-                    # queue, so a blocked put wakes within one timeout.
-                    while not self._stop.is_set():
-                        try:
-                            self._q.put(item, timeout=0.05)
-                            break
-                        except queue.Full:
-                            continue
-                    if self._stop.is_set():
+                    if not put(item):
                         return
             except BaseException as e:          # surfaced on next()
                 self._err = e
             finally:
-                try:
-                    self._q.put_nowait(self._done)
-                except queue.Full:
-                    pass                        # close() is draining anyway
+                # The end waits for room like an item: dropped from a full
+                # queue, it would leave the consumer blocked in ``get``
+                # after the last item.
+                put(self._done)
 
         self._t = threading.Thread(target=worker, daemon=True)
         self._t.start()

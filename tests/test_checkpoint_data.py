@@ -1,6 +1,7 @@
 """Checkpoint store + deterministic data pipeline."""
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +158,41 @@ def test_prefetch_iterator():
     assert next(it) == 1
     with pytest.raises(RuntimeError):
         next(it)
+
+
+@pytest.mark.parametrize("end", ["exhausted", "raised"])
+def test_prefetch_end_reaches_consumer_past_a_full_queue(end):
+    """The source ends, or raises, while the queue is full: the consumer
+    still gets every item and then the end, instead of blocking in
+    ``get`` for good."""
+    ended = threading.Event()
+
+    def source():
+        yield 0
+        yield 1                            # the queue (depth 2) is full
+        ended.set()
+        if end == "raised":
+            raise RuntimeError("io error")
+
+    it = PrefetchIterator(source(), depth=2)
+    assert ended.wait(timeout=5.0)
+    time.sleep(0.1)                        # the worker reaches its end
+    got = []
+
+    def consume():
+        try:
+            for x in it:
+                got.append(x)
+        except RuntimeError as e:
+            got.append(str(e))
+
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    t.join(timeout=5.0)
+    stuck = t.is_alive()
+    it.close()
+    assert not stuck
+    assert got == ([0, 1, "io error"] if end == "raised" else [0, 1])
 
 
 def test_prefetch_close_unblocks_worker():

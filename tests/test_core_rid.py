@@ -220,6 +220,64 @@ def test_rid_reconstructs(kind, cplx, dtype):
                                np.asarray(A[:, np.asarray(dec.J)]), atol=0)
 
 
+@pytest.mark.parametrize("outer_jit", [False, True])
+@pytest.mark.parametrize("piv,n,path", [
+    ("unsorted", 640, "slices"), ("unsorted", 48, "take"),
+    ("k1", 640, "slices"), ("k1", 48, "take"), ("kn", 48, "take")])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.complex64, jnp.float64,
+                                   jnp.complex128])
+def test_take_is_the_column_gather(dtype, piv, n, path, outer_jit):
+    """``_take(A, piv)`` is ``A[:, piv]`` bit for bit on both sides of
+    ``gather_path``: pivots unsorted, the first and last columns among
+    them, ``k = 1`` and ``k = n``, alone and inside a caller's jit."""
+    from repro.core.rid import _take, gather_path
+    piv = {"unsorted": [n - 1, 3, 0, 22, 5], "k1": [17],
+           "kn": list(range(n))[::-1]}[piv]
+    assert gather_path(len(piv), n) == path
+    A = lowrank(jax.random.key(30), 64, n, 4, dtype=jnp.finfo(dtype).dtype,
+                cplx=jnp.issubdtype(dtype, jnp.complexfloating))
+    assert A.dtype == dtype
+    J = jnp.asarray(piv, jnp.int32)
+    take = jax.jit(lambda A, J: _take(A, J)) if outer_jit else _take
+    B = take(A, J)
+    assert B.dtype == A.dtype
+    np.testing.assert_array_equal(np.asarray(B), np.asarray(A)[:, piv])
+
+
+@pytest.mark.parametrize("n,path", [(512, "slices"), (48, "take")])
+def test_take_touches_no_whole_matrix(n, path):
+    """On the slices side the gather's program holds no ``gather`` and no
+    equation with as many elements as ``A``: each step moves one column.
+    Past the crossover it is the one ``gather`` of ``jnp.take``."""
+    from repro.analysis.jaxpr import iter_eqns
+    from repro.core.rid import _take, gather_path
+    m, k = 64, 4
+    assert gather_path(k, n) == path
+    jaxpr = jax.make_jaxpr(_take)(jax.ShapeDtypeStruct((m, n), jnp.float32),
+                                  jax.ShapeDtypeStruct((k,), jnp.int32))
+    eqns = list(iter_eqns(jaxpr.jaxpr))
+    prims = {e.primitive.name for e in eqns}
+    if path == "slices":
+        assert "dynamic_slice" in prims and "gather" not in prims
+        assert all(np.prod(v.aval.shape) < m * n for e in eqns
+                   for v in e.outvars)
+    else:
+        assert "gather" in prims and "while" not in prims
+
+
+@pytest.mark.parametrize("k,n,path", [
+    (100, 2 ** 14, "slices"), (400, 2 ** 14, "take"),
+    (1000, 2 ** 16, "take"), (1000, 2 ** 18, "slices"),
+    (128, 2 ** 14, "slices"), (129, 2 ** 14, "take")])
+def test_gather_path_rule(k, n, path):
+    """The shape rule: the paper grid's k = 100 of n = 2^14 (rows 1-2) and
+    k = 1000 of 2^18 (row 8) copy columns; k = 400 of 2^14 (rows 3-4) and
+    1000 of 2^16 (row 6) take; the crossover sits at k = n / 128 (the
+    measured table in PERF.md)."""
+    from repro.core.rid import gather_path
+    assert gather_path(k, n) == path
+
+
 def test_rsvd_matches_dense_svd():
     key = jax.random.key(9)
     A = lowrank(key, 300, 220, 10, cplx=True)
@@ -340,8 +398,9 @@ def test_rid_bits_unchanged_by_tracing(kind, cplx, dtype):
 
 def test_rid_span_tree():
     """``rid`` > ``rid.sketch`` / ``rid.qr_interp`` / ``rid.gather``, the
-    root carrying the shape and ``rid.sketch``, for srft, the path
-    ``srft_path`` picks; ``rid_from_sketch`` alone opens its two."""
+    root carrying the shape, ``rid.sketch``, for srft, the path
+    ``srft_path`` picks and ``rid.gather`` the path ``gather_path`` picks;
+    ``rid_from_sketch`` alone opens its two."""
     from repro.core import rid_from_sketch
     from repro.core.sketch import SRFT_DENSE_MAX_PLANE_ROWS
     from repro.obs import tracing
@@ -359,6 +418,13 @@ def test_rid_span_tree():
         ("rid.gather", root.index, 1)]
     assert all(root.t0 <= s.t0 and s.t1 <= root.t1 for s in children)
     assert children[0].attrs == {}
+    assert children[2].attrs == {"gather_path": "take"}
+    for n, path in ((512, "slices"), (48, "take")):
+        B = lowrank(jax.random.key(5), 64, n, 4, dtype=jnp.float32)
+        with tracing() as tr:
+            rid(jax.random.key(6), B, 4, sketch_kind="gaussian")
+        assert [s.attrs for s in tr.spans if s.name == "rid.gather"] == [
+            {"gather_path": path}]
     for l, path in ((8, "dense"), (SRFT_DENSE_MAX_PLANE_ROWS + 1, "fft")):
         with tracing() as tr:
             rid(jax.random.key(6), A, 4, l=l, sketch_kind="srft")
